@@ -18,6 +18,7 @@ import jax
 import numpy as np
 
 from repro.api import ServeConfig, ServeEngine, ServeRequest  # lazy jax-backed names
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_smoke_config
 from repro.models import init_params, model_defs
 
@@ -31,6 +32,7 @@ def main() -> None:
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=128)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config("deepseek-7b")
     params = init_params(model_defs(cfg), jax.random.PRNGKey(0), cfg.param_jdtype())

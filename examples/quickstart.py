@@ -15,6 +15,7 @@ import sys
 sys.path.insert(0, "src")
 
 from repro.api import Trainer, TrainConfig  # jax-backed names resolve lazily
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_smoke_config
 from repro.data.pipeline import DataConfig, make_train_iter
 
@@ -26,6 +27,7 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=64)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch)
     tcfg = TrainConfig(microbatches=2)

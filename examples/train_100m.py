@@ -22,6 +22,7 @@ from dataclasses import replace
 import jax
 
 from repro.ckpt.checkpoint import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, get_smoke_config
 from repro.data.pipeline import DataConfig, make_train_iter
 from repro.optim import AdamWConfig, ScheduleConfig
@@ -37,6 +38,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--small", action="store_true", help="reduced width for quick CPU runs")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config("mamba2-130m") if args.small else get_config("mamba2-130m")
     if not args.small:

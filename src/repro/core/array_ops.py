@@ -7,9 +7,8 @@ the strictly-sequential ``np.add.accumulate`` bandwidth-pointer fold in
 the sorted-membership probes (stat-slot lookup, the batched VMEM cache-tag
 probe), and the batched backend's segment-scatter landing kernel
 (``repro.sim.batched``).  Each primitive has a NumPy reference
-implementation and a jit-compiled jax implementation (pallas for the
-segment-scatter kernel, where a fused scatter pays on accelerator), selected
-by ``SimConfig.array_backend = "numpy" | "jax"``.
+implementation and a jit-compiled jax implementation, selected by
+``SimConfig.array_backend = "numpy" | "jax"``.
 
 The contract is **element identity**: for every op and every input, the jax
 backend must return exactly the NumPy reference's values — uint64 scatters
@@ -128,11 +127,11 @@ class NumpyOps(ArrayOps):
 class JaxOps(ArrayOps):
     """jit-compiled jax backend, element-identical to :class:`NumpyOps`.
 
-    All ops run under ``jax.experimental.enable_x64`` (scoped, not the
-    global flag — the serving stack's float32 jax code is untouched) so
-    uint64/int64/float64 semantics match NumPy exactly.  The segment-scatter
-    landing kernel is a pallas kernel (interpreter mode off-TPU), the one
-    call site where a fused VMEM scatter pays on real accelerator runs.
+    All ops run under ``jax.enable_x64(True)`` (scoped, not the global
+    flag — the serving stack's float32 jax code is untouched) so
+    uint64/int64/float64 semantics match NumPy exactly.  The segment
+    scatter is the same jitted XLA scatter as :meth:`scatter_add_u64`, with
+    events past the final report boundary masked to a zero add.
     """
 
     name = "jax"
@@ -141,11 +140,8 @@ class JaxOps(ArrayOps):
         import jax
         import jax.numpy as jnp
 
-        self._jax = jax
         self._jnp = jnp
-        from jax.experimental import enable_x64
-
-        self._x64 = enable_x64
+        self._x64 = lambda: jax.enable_x64(True)
 
         def _scatter(dense, lin, cnt):
             return dense.at[lin].add(cnt)
@@ -164,10 +160,18 @@ class JaxOps(ArrayOps):
             idx = jnp.clip(jnp.searchsorted(table, values), 0, table.shape[0] - 1)
             return table[idx] == values
 
+        def _segment(seg, lin, cnt, n_segs, row_size):
+            # a masked-out event adds zero at cell 0: shapes stay static
+            ok = seg < n_segs
+            cell = jnp.where(ok, seg * row_size + lin, 0)
+            add = jnp.where(ok, cnt, jnp.uint64(0))
+            table = jnp.zeros((n_segs * row_size,), jnp.uint64).at[cell].add(add)
+            return table.reshape(n_segs, row_size)
+
         self._scatter = jax.jit(_scatter)
         self._runsum = jax.jit(_runsum)
         self._member = jax.jit(_member)
-        self._seg_kernels: Dict = {}
+        self._segment = jax.jit(_segment, static_argnums=(3, 4))
 
     def scatter_add_u64(self, dense_flat, lin, cnt):
         with self._x64():
@@ -192,61 +196,16 @@ class JaxOps(ArrayOps):
                 self._member(self._jnp.asarray(values), self._jnp.asarray(table))
             )
 
-    def _segment_kernel(self, n_segs: int, row_size: int):
-        """Build (and cache) the pallas segment-scatter kernel for one table
-        shape.  One grid cell; a ``fori_loop`` walks the event columns and
-        accumulates into the VMEM-resident output table.  ``interpret=True``
-        keeps it runnable on CPU hosts (see /opt guide: pallas quickstart)."""
-        key = (n_segs, row_size)
-        kern = self._seg_kernels.get(key)
-        if kern is not None:
-            return kern
-        jax = self._jax
-        jnp = self._jnp
-        from jax.experimental import pallas as pl
-
-        def kernel(seg_ref, lin_ref, cnt_ref, out_ref):
-            out_ref[...] = jnp.zeros((n_segs, row_size), dtype=jnp.uint64)
-            n = seg_ref.shape[0]
-
-            def body(i, carry):
-                s = seg_ref[i]
-                l = lin_ref[i]
-                c = cnt_ref[i]
-                # mask events past the final boundary instead of branching:
-                # a masked-out event lands a zero on row 0 (dynamic shapes
-                # are not expressible; masking is the pallas idiom).
-                ok = s < n_segs
-                row = jnp.where(ok, s, 0)
-                col = jnp.where(ok, l, 0)
-                add = jnp.where(ok, c, jnp.uint64(0))
-                out_ref[row, col] = out_ref[row, col] + add
-                return carry
-
-            jax.lax.fori_loop(0, n, body, 0)
-
-        def run(seg, lin, cnt):
-            return pl.pallas_call(
-                kernel,
-                out_shape=jax.ShapeDtypeStruct((n_segs, row_size), jnp.uint64),
-                interpret=True,
-            )(seg, lin, cnt)
-
-        kern = jax.jit(run)
-        self._seg_kernels[key] = kern
-        return kern
-
     def segment_scatter(self, seg, lin, cnt, n_segs, row_size):
         seg = np.asarray(seg, dtype=np.int64)
         lin = np.asarray(lin, dtype=np.int64)
         cnt = np.asarray(cnt, dtype=np.uint64)
         if seg.size == 0 or n_segs == 0:
             return np.zeros((n_segs, row_size), dtype=np.uint64)
-        kern = self._segment_kernel(int(n_segs), int(row_size))
         with self._x64():
             return np.asarray(
-                kern(self._jnp.asarray(seg), self._jnp.asarray(lin),
-                     self._jnp.asarray(cnt))
+                self._segment(self._jnp.asarray(seg), self._jnp.asarray(lin),
+                              self._jnp.asarray(cnt), int(n_segs), int(row_size))
             )
 
 

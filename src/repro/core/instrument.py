@@ -62,19 +62,6 @@ class StepCost:
     hbm_bytes: float = 0.0
     collective_bytes: float = 0.0
 
-    @classmethod
-    def from_compiled(cls, compiled, collective_bytes: float = 0.0) -> "StepCost":
-        ca = {}
-        try:
-            ca = compiled.cost_analysis() or {}
-        except Exception:  # backends may not implement cost analysis
-            ca = {}
-        return cls(
-            flops=float(ca.get("flops", 0.0)),
-            hbm_bytes=float(ca.get("bytes accessed", 0.0)),
-            collective_bytes=float(collective_bytes),
-        )
-
 
 @dataclass
 class StepRecord:

@@ -33,14 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # TPU scratch memory spaces (importable on any backend)
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover - very old jax
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention_pallas"]
 
@@ -175,9 +168,9 @@ def flash_attention_pallas(
         out_specs=pl.BlockSpec((1, 1, q_blk, D), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq_p, D), q.dtype),
         scratch_shapes=[
-            _VMEM((q_blk,), jnp.float32),
-            _VMEM((q_blk,), jnp.float32),
-            _VMEM((q_blk, D), jnp.float32),
+            pltpu.VMEM((q_blk,), jnp.float32),
+            pltpu.VMEM((q_blk,), jnp.float32),
+            pltpu.VMEM((q_blk, D), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)

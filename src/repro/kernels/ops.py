@@ -200,21 +200,28 @@ def ssd_scan(
     chunk: int = 128,
     impl: str = "auto",
 ) -> Tuple[jax.Array, jax.Array]:
-    """Chunked SSD scan.  Returns (y (B,S,H,P), final state (B,H,P,N))."""
+    """Chunked SSD scan.  Returns (y (B,S,H,P), final state (B,H,P,N)).
+
+    A ragged sequence is padded up to a whole number of chunks with
+    ``dt = 0`` steps, which leave the state untouched (decay ``exp(0) = 1``,
+    zero input), so the padded tail changes neither the kept outputs nor
+    the final state."""
     if impl == "auto":
         impl = "pallas" if _on_tpu() else "xla"
-    S = x.shape[1]
-    chunk = min(chunk, S)
-    while S % chunk != 0:  # shrink to a divisor for ragged smoke shapes
-        chunk //= 2
-        if chunk == 0:
-            raise ValueError(f"no chunk divides seq len {S}")
     if impl == "ref":
         y, h = _ref.ssd_ref(x, dt, A, Bm, Cm, D, h0=h0, return_state=True)
         return y, h
+    S = x.shape[1]
+    chunk = min(chunk, S)
+    pad = (-S) % chunk
+    if pad:
+        x, dt, Bm, Cm = (
+            jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+            for a in (x, dt, Bm, Cm)
+        )
     if impl == "xla":
         y, h = _ref.ssd_chunked_ref(x, dt, A, Bm, Cm, D, h0=h0, chunk=chunk, return_state=True)
-        return y, h
+        return y[:, :S], h
     if impl == "pallas":
         y, h = ssd_scan_pallas(
             jnp.swapaxes(x, 1, 2),
@@ -227,5 +234,5 @@ def ssd_scan(
             chunk=chunk,
             interpret=not _on_tpu(),
         )
-        return jnp.swapaxes(y, 1, 2), h
+        return jnp.swapaxes(y, 1, 2)[:, :S], h
     raise ValueError(f"unknown impl {impl!r}")

@@ -137,7 +137,6 @@ def ssd_chunked_ref(
 
     # chunk summaries: state contribution of each chunk
     seg = jnp.exp(cum[:, :, -1:, :] - cum)  # exp(s_L − s_s)
-    states = jnp.einsum("bclh,bclhn,bclhp->bhpn", jnp.zeros_like(seg), Bf, xf)  # init only
     states = jnp.einsum("bclh,bclhn,bclhp->bchpn", seg * dtf, Bf, xf)
 
     # inter-chunk recurrence over chunk summaries

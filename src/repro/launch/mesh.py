@@ -25,12 +25,7 @@ __all__ = ["make_production_mesh", "make_tiny_mesh", "mesh_axis_sizes", "dp_axes
 
 
 def _mk(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    # AxisType landed after jax 0.4.x; older jax defaults every axis to Auto,
-    # which is exactly what we request on newer versions.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -123,7 +123,8 @@ def model_defs(cfg: ModelConfig) -> Dict[str, Any]:
 
 # ============================================================== layer application
 def _apply_mixer(
-    lp, x, cfg: ModelConfig, positions, *, causal, prefix_len, attn_impl, return_cache=False
+    lp, x, cfg: ModelConfig, positions, *, causal, prefix_len, attn_impl, ssd_impl,
+    return_cache=False,
 ):
     h = rmsnorm(lp["ln1"], x, cfg.rms_eps)
     if "attn" in lp:
@@ -139,7 +140,9 @@ def _apply_mixer(
                 return_cache=return_cache, attn_impl=attn_impl,
             )
     else:
-        out = mamba_mod.mamba_apply(lp["ssm"], h, cfg, return_cache=return_cache)
+        out = mamba_mod.mamba_apply(
+            lp["ssm"], h, cfg, return_cache=return_cache, ssd_impl=ssd_impl
+        )
     if return_cache:
         mixed, cache = out
         return x + mixed, cache
@@ -161,18 +164,19 @@ def _apply_ffn(lp, x, cfg: ModelConfig):
 
 def _apply_layer_full(
     lp, x, cfg: ModelConfig, positions, *,
-    causal=True, prefix_len=0, attn_impl="auto", enc_out=None, cross_kv=None,
-    return_cache=False,
+    causal=True, prefix_len=0, attn_impl="auto", ssd_impl="auto", enc_out=None,
+    cross_kv=None, return_cache=False,
 ):
     """One full layer on a full sequence. Returns (x, aux, cache|None)."""
     if return_cache:
         x, mixer_cache = _apply_mixer(
             lp, x, cfg, positions, causal=causal, prefix_len=prefix_len,
-            attn_impl=attn_impl, return_cache=True,
+            attn_impl=attn_impl, ssd_impl=ssd_impl, return_cache=True,
         )
     else:
         x = _apply_mixer(
-            lp, x, cfg, positions, causal=causal, prefix_len=prefix_len, attn_impl=attn_impl
+            lp, x, cfg, positions, causal=causal, prefix_len=prefix_len,
+            attn_impl=attn_impl, ssd_impl=ssd_impl,
         )
         mixer_cache = None
     if "cross" in lp and enc_out is not None:
@@ -227,7 +231,9 @@ def _assemble_input(cfg: ModelConfig, params, batch: Dict[str, jax.Array]):
     return x, prefix_len
 
 
-def _run_stack(cfg, params, x, positions, *, prefix_len, attn_impl, enc_out, collect_cache):
+def _run_stack(
+    cfg, params, x, positions, *, prefix_len, attn_impl, ssd_impl, enc_out, collect_cache
+):
     """Prefix layers + scanned superblocks.  Returns (x, aux, caches)."""
     n_prefix, period, repeats = num_layers_in_stack(cfg)
     aux_total = jnp.zeros((), jnp.float32)
@@ -235,8 +241,8 @@ def _run_stack(cfg, params, x, positions, *, prefix_len, attn_impl, enc_out, col
     for j in range(n_prefix):
         x, aux, c = _apply_layer_full(
             params[f"prefix_{j}"], x, cfg, positions,
-            prefix_len=prefix_len, attn_impl=attn_impl, enc_out=enc_out,
-            return_cache=collect_cache,
+            prefix_len=prefix_len, attn_impl=attn_impl, ssd_impl=ssd_impl,
+            enc_out=enc_out, return_cache=collect_cache,
         )
         aux_total = aux_total + aux
         prefix_caches.append(c)
@@ -247,8 +253,8 @@ def _run_stack(cfg, params, x, positions, *, prefix_len, attn_impl, enc_out, col
         for p in range(period):
             y, aux, c = _apply_layer_full(
                 lp[f"pos_{p}"], y, cfg, positions,
-                prefix_len=prefix_len, attn_impl=attn_impl, enc_out=enc_out,
-                return_cache=collect_cache,
+                prefix_len=prefix_len, attn_impl=attn_impl, ssd_impl=ssd_impl,
+                enc_out=enc_out, return_cache=collect_cache,
             )
             aux_c = aux_c + aux
             cache_p[f"pos_{p}"] = c
@@ -266,16 +272,20 @@ def forward(
     batch: Dict[str, jax.Array],
     *,
     attn_impl: str = "auto",
+    ssd_impl: str = "auto",
 ) -> Tuple[jax.Array, jax.Array]:
-    """Training forward pass → (logits, aux_loss)."""
+    """Training forward pass → (logits, aux_loss).
+
+    ``attn_impl`` / ``ssd_impl`` pick the attention and SSD kernels
+    (:mod:`repro.kernels.ops`); a differentiated caller asks for ``"xla"``."""
     enc_out = None
     if cfg.encdec:
         enc_out = _encode(cfg, params, batch["enc_embeds"], attn_impl)
     x, prefix_len = _assemble_input(cfg, params, batch)
     positions = jnp.broadcast_to(jnp.arange(x.shape[1])[None], x.shape[:2])
     x, aux, _ = _run_stack(
-        cfg, params, x, positions,
-        prefix_len=prefix_len, attn_impl=attn_impl, enc_out=enc_out, collect_cache=False,
+        cfg, params, x, positions, prefix_len=prefix_len, attn_impl=attn_impl,
+        ssd_impl=ssd_impl, enc_out=enc_out, collect_cache=False,
     )
     x = rmsnorm(params["final_norm"], x, cfg.rms_eps)
     if cfg.vision_tokens > 0 and "vision_embeds" in batch:
@@ -337,8 +347,8 @@ def prefill(
     x, prefix_len = _assemble_input(cfg, params, batch)
     positions = jnp.broadcast_to(jnp.arange(x.shape[1])[None], x.shape[:2])
     x, aux, (prefix_caches, stack_caches) = _run_stack(
-        cfg, params, x, positions,
-        prefix_len=prefix_len, attn_impl=attn_impl, enc_out=enc_out, collect_cache=True,
+        cfg, params, x, positions, prefix_len=prefix_len, attn_impl=attn_impl,
+        ssd_impl="auto", enc_out=enc_out, collect_cache=True,
     )
     x = rmsnorm(params["final_norm"], x, cfg.rms_eps)
     logits = logits_apply(params["embed"], params.get("lm_head"), x[:, -1:], cfg)[:, 0]

@@ -184,15 +184,7 @@ def summarize_compiled(compiled, hlo_text: Optional[str] = None) -> HloCostSumma
     ``cost_analysis`` flops/bytes on an SPMD executable are *per device*
     (shapes in the module are already partitioned).
     """
-    ca = {}
-    try:
-        ca = compiled.cost_analysis() or {}
-    except Exception:
-        ca = {}
-    # jax <= 0.4.x returns a list with one dict per program; newer jax
-    # returns the dict directly.  Normalize to the dict.
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
+    ca = compiled.cost_analysis()
     text = hlo_text if hlo_text is not None else compiled.as_text()
     colls = parse_collectives(text)
     breakdown: Dict[str, float] = defaultdict(float)
@@ -202,17 +194,12 @@ def summarize_compiled(compiled, hlo_text: Optional[str] = None) -> HloCostSumma
         breakdown[base] += op.wire_bytes
         wire += op.wire_bytes
 
-    mem = None
-    try:
-        mem = compiled.memory_analysis()
-    except Exception:
-        mem = None
-
-    arg_b = float(getattr(mem, "argument_size_in_bytes", 0) or 0)
-    out_b = float(getattr(mem, "output_size_in_bytes", 0) or 0)
-    tmp_b = float(getattr(mem, "temp_size_in_bytes", 0) or 0)
-    alias_b = float(getattr(mem, "alias_size_in_bytes", 0) or 0)
-    gen_b = float(getattr(mem, "generated_code_size_in_bytes", 0) or 0)
+    mem = compiled.memory_analysis()
+    arg_b = float(mem.argument_size_in_bytes)
+    out_b = float(mem.output_size_in_bytes)
+    tmp_b = float(mem.temp_size_in_bytes)
+    alias_b = float(mem.alias_size_in_bytes)
+    gen_b = float(mem.generated_code_size_in_bytes)
 
     return HloCostSummary(
         flops_per_device=float(ca.get("flops", 0.0)),

@@ -435,11 +435,10 @@ class BatchRunner:
     Two backends:
 
     * ``backend="pool"`` (default) — one simulation per job.  The pooled
-      path orders jobs shape-grouped (same-shape jobs land in the same pool
-      chunk) and maps with an explicit ``chunksize`` so small-job sweeps
-      stop paying one IPC round-trip per job; payloads are restored to job
-      order before merging, so the pooled and serial paths stay
-      bit-identical.
+      path hands jobs out one at a time in shape-grouped order (a worker's
+      trace/descriptor caches stay warm across neighbouring jobs) and reads
+      each result under a timeout; payloads are restored to job order
+      before merging, so the pooled and serial paths stay bit-identical.
     * ``backend="vector"`` — shape-grouped trace-compile/replay: each
       distinct shape simulates once (the compiled engine's phase 1) and all
       its jobs replay in lockstep (phase 2).  Cross-shape groups still fan
@@ -611,24 +610,18 @@ class BatchRunner:
                     payloads[i] = self._run_one(i, jobs[i], first_attempt=0)
                     self._journal_append(jfh, i, payloads[i])
                 return payloads  # type: ignore[return-value]
-            # Shape-grouped order: one chunk tends to hold one shape's jobs,
-            # so a worker's trace/descriptor caches stay warm within a chunk.
-            # One job per chunk under an injecting plan: a crash/hang must
-            # take down only its own job, never innocent chunk-mates.
+            # Shape-grouped order keeps a worker's trace/descriptor caches
+            # warm.  One job per task: only imap's per-task iterator takes a
+            # timeout (a larger chunksize returns a plain generator), and a
+            # crash/hang then takes down only its own job.
             pending_set = set(pending)
             order = [i for grp in self._shape_groups() for i in grp
                      if i in pending_set]
-            injecting = plan is not None and bool(plan.crash_jobs or plan.hang_jobs)
-            chunksize = 1 if injecting else max(
-                1, (len(order) + 4 * self.workers - 1) // (4 * self.workers))
             timeout = plan.job_timeout_s if plan is not None else _DEFAULT_JOB_TIMEOUT_S
             finished = 0
             if order:
                 with _pool_context().Pool(self.workers) as pool:
-                    it = pool.imap(
-                        _pool_worker, [(i, jobs[i], plan) for i in order],
-                        chunksize=chunksize,
-                    )
+                    it = pool.imap(_pool_worker, [(i, jobs[i], plan) for i in order])
                     try:
                         for k, i in enumerate(order):
                             # per-result timeout: a dead/hung worker surfaces
@@ -674,8 +667,12 @@ class BatchRunner:
 
     def run(self, parallel: bool = True) -> BatchResult:
         t0 = time.perf_counter()
+        # A job with array_backend="jax" takes the accelerator in whichever
+        # process runs it, and an accelerator belongs to one process: pooled
+        # workers would each try to take it, so such sweeps run in process.
+        jax_jobs = any(dict(j.config).get("array_backend") == "jax" for j in self.jobs)
         use_pool = (parallel and self.workers > 1 and len(self.jobs) > 1
-                    and self.backend != "batched")
+                    and self.backend != "batched" and not jax_jobs)
         if self.backend == "vector":
             payloads = self._run_vector(use_pool)
         elif self.backend == "batched":

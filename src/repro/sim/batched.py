@@ -25,7 +25,7 @@ every run's whole journal through the array-ops backend:
   indices.
 * **One segment-scatter landing kernel.**  All runs' report increments land
   into one padded ``(runs, segments, slot*type*outcome)`` uint64 tensor via
-  :meth:`ArrayOps.segment_scatter` (numpy reference or the jax/pallas
+  :meth:`ArrayOps.segment_scatter` (numpy reference or the jitted jax
   kernel), and a cumulative sum down the segment axis yields every report's
   cumulative matrix — the columnar analog of "each retire prints the
   cumulative table so far".

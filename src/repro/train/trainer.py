@@ -27,6 +27,7 @@ from repro.optim import (
     ef_state_init,
     learning_rate,
 )
+from repro.perf.hlo import summarize_compiled
 
 __all__ = ["TrainConfig", "make_train_step", "make_loss_fn", "Trainer", "cross_entropy"]
 
@@ -60,7 +61,9 @@ def cross_entropy(logits: jax.Array, labels: jax.Array, z_loss: float = 0.0):
 
 def make_loss_fn(cfg: ModelConfig, tcfg: TrainConfig) -> Callable:
     def loss_fn(params, batch):
-        logits, aux = forward(cfg, params, batch)
+        # The Pallas attention and SSD kernels have no backward pass yet, so
+        # the differentiated forward takes the blocked-jnp paths.
+        logits, aux = forward(cfg, params, batch, attn_impl="xla", ssd_impl="xla")
         loss, n_tok = cross_entropy(logits, batch["labels"], tcfg.z_loss)
         total = loss + tcfg.aux_weight * aux
         return total, {"loss": loss, "aux": aux, "tokens": n_tok}
@@ -178,6 +181,8 @@ class Trainer:
         self.eval_fn = jax.jit(lambda p, b: make_loss_fn(cfg, tcfg)(p, b)[1])
         self.step = 0
         self._step_cost: Optional[StepCost] = None
+        #: the train step compiled once, on the first batch's shapes
+        self._compiled_step = None
 
     def restore_or_init(self):
         if self.ckpt is not None:
@@ -192,22 +197,17 @@ class Trainer:
         history = []
         for _ in range(num_steps):
             batch = next(self.data_iter)
+            if self._compiled_step is None:
+                # compile once; the stream's per-step cost comes from the
+                # same executable that runs
+                self._compiled_step = self.step_fn.lower(params, opt_state, batch).compile()
+                s = summarize_compiled(self._compiled_step)
+                self._step_cost = StepCost(
+                    s.flops_per_device, s.hbm_bytes_per_device, s.collective_wire_bytes_per_device
+                )
             uid = self.stats.step_begin("train_step", self.train_stream)
-            params, opt_state, metrics = self.step_fn(params, opt_state, batch)
+            params, opt_state, metrics = self._compiled_step(params, opt_state, batch)
             metrics = jax.tree_util.tree_map(lambda x: x.block_until_ready(), metrics)
-            if self._step_cost is None:
-                try:  # attribute compiled cost to the stream (once)
-                    from repro.perf.hlo import summarize_compiled
-
-                    lowered = jax.jit(make_train_step(self.cfg, self.tcfg)).lower(
-                        params, opt_state, batch
-                    )
-                    s = summarize_compiled(lowered.compile())
-                    self._step_cost = StepCost(
-                        s.flops_per_device, s.hbm_bytes_per_device, s.collective_wire_bytes_per_device
-                    )
-                except Exception:
-                    self._step_cost = StepCost()
             self.stats.step_end(
                 uid,
                 tokens=int(metrics["tokens"]),

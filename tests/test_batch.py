@@ -86,9 +86,9 @@ class TestBatchRunner:
         assert [p["scenario"] for p in result.payloads] == [j.scenario for j in SMALL_SWEEP]
 
     def test_pooled_chunked_shape_grouped_order_restored(self):
-        """The pooled path reorders jobs shape-grouped and maps with a
-        chunksize; payloads must come back in job order and bit-identical to
-        serial even with interleaved duplicate shapes."""
+        """The pooled path hands jobs out in shape-grouped order; payloads
+        must come back in job order and bit-identical to serial even with
+        interleaved duplicate shapes."""
         jobs = [
             SMALL_SWEEP[0], SMALL_SWEEP[1], SMALL_SWEEP[0], SMALL_SWEEP[2],
             SMALL_SWEEP[1], SMALL_SWEEP[0],

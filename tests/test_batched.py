@@ -273,6 +273,20 @@ class TestJaxBackendEndToEnd:
             assert pn["signature"] == pj["signature"]
             assert pn["cycles"] == pj["cycles"] and pn["oracle"] == pj["oracle"]
 
+    @pytest.mark.parametrize("backend", ["pool", "vector"])
+    def test_jax_jobs_never_fan_out_to_workers(self, backend):
+        """One process per accelerator: a sweep with a jax-backed job runs
+        in process even when a pool was asked for, with the same payloads."""
+        jobs = [
+            BatchJob.make("l2_lat", {"n_loads": 16 + 16 * i},
+                          config=dict(array_backend="jax"))
+            for i in range(3)
+        ]
+        pooled = BatchRunner(jobs, workers=3, backend=backend).run(parallel=True)
+        serial = BatchRunner(jobs, backend=backend).run(parallel=False)
+        assert not pooled.parallel and pooled.workers == 1
+        assert pooled.signature() == serial.signature()
+
 
 # --------------------------------------------------------------------------- hypothesis
 if HAVE_HYPOTHESIS:

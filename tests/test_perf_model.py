@@ -47,15 +47,7 @@ class TestLoopAwareCosts:
         assert r.flops == pytest.approx(outer * inner * 2 * N**3, rel=0.01)
 
     def test_matches_cost_analysis_without_loops(self):
-        """Loop-free module: our count must bracket XLA's own cost analysis.
-
-        ``Compiled.cost_analysis()`` changed shape across jaxlib versions —
-        older releases return ``[{...}]`` (one properties dict per program),
-        newer ones return the dict directly.  The seed assumed the dict form
-        and died with ``TypeError: list indices must be integers`` on the
-        pinned jaxlib; normalizing the return restores the original
-        assertion (the cost model itself was never wrong).
-        """
+        """Loop-free module: our count must bracket XLA's own cost analysis."""
 
         def f(a, b):
             return jax.nn.relu(a @ b)
@@ -65,8 +57,6 @@ class TestLoopAwareCosts:
         compiled = jax.jit(f).lower(a, b).compile()
         mine = analyze_compiled(compiled)
         xla = compiled.cost_analysis()
-        if isinstance(xla, (list, tuple)):
-            xla = xla[0]
         assert mine.flops == pytest.approx(2 * 64 * 128 * 32, rel=0.01)
         # XLA counts the relu's elementwise flops too; dot dominates
         assert mine.flops <= xla["flops"] <= mine.flops * 1.1

@@ -18,8 +18,6 @@ CODE = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.train.pipeline import pipeline_forward, split_stages
 
-# jax.make_mesh grew its axis_types kwarg after the pinned 0.4.x line; plain
-# Auto axes are that version's default, so the two-arg call is equivalent.
 mesh = jax.make_mesh((4, 2), ("stage", "data"))
 
 L, D, M, MB = 8, 16, 6, 4

@@ -58,13 +58,47 @@ def test_framework_streams_integration():
 
 
 def test_quickstart_example_runs():
+    import os
     import subprocess
     import sys
 
     proc = subprocess.run(
         [sys.executable, "examples/quickstart.py", "--steps", "3"],
         capture_output=True, text=True, timeout=600,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root"},
+        env={
+            "PYTHONPATH": "src",
+            "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
+            "HOME": os.environ.get("HOME", ""),
+            # the child runs on the host backend, never on an accelerator
+            # this test process may hold
+            "JAX_PLATFORMS": "cpu",
+        },
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "stream" in proc.stdout
+
+
+def test_compile_cache_left_to_jax_or_fixed_in_checkout(monkeypatch, tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` set: no directory is set in code.
+    Unset: the cache goes to one fixed, git-ignored path in the checkout."""
+    from pathlib import Path
+
+    import jax
+
+    from repro.compile_cache import CHECKOUT_CACHE_DIR, enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(CHECKOUT_CACHE_DIR)
+        assert enable_compile_cache() == str(CHECKOUT_CACHE_DIR)  # same path every call
+        assert jax.config.jax_compilation_cache_dir == str(CHECKOUT_CACHE_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    root = Path(__file__).resolve().parents[1]
+    assert CHECKOUT_CACHE_DIR == root / ".jax_cache"
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split("\n")
