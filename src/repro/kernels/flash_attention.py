@@ -173,5 +173,6 @@ def flash_attention_pallas(
             pltpu.VMEM((q_blk, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
     return out_p[:, :, :Sq, :]

@@ -169,6 +169,7 @@ def ssd_scan_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(
         x, dt[:, :, None, :], A.astype(jnp.float32), Bm, Cm,
         D.astype(jnp.float32), h0,

@@ -328,6 +328,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, *, enc_len: int = 0, 
     return cache
 
 
+@jax.named_scope("prefill")
 def prefill(
     cfg: ModelConfig,
     params,
@@ -357,6 +358,7 @@ def prefill(
     return logits, cache
 
 
+@jax.named_scope("decode_step")
 def decode_step(
     cfg: ModelConfig,
     params,

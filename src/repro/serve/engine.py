@@ -35,6 +35,15 @@ Retirement folds the stream's step records into a constant-size aggregate
 (:meth:`StreamStats.retire_stream`), so a long-running engine holds O(live)
 step state no matter how many requests it has served.
 
+Every phase of a step is a program span on the one recorder
+(``repro.core.instrument.SPANS``, also a profiler annotation):
+``engine.step`` holds ``engine.admit`` (per request ``engine.prefill`` and
+``engine.place``), ``engine.decode`` and ``engine.stats`` (per-stream
+records and lanes, with ``engine.finish`` for each request that retires);
+``engine.queued`` is a request's wait from :meth:`Engine.submit` to its
+prefill.  ``Request.prefill_s``, ``ttft_s`` and ``decode_s`` are read from
+these spans.
+
 Without the stream dimension these numbers are exactly the conflated
 aggregates the paper complains about — see ``benchmarks/serving.py`` for the
 side-by-side, and ``serve/loadgen.py`` for the trace-driven multi-tenant
@@ -55,6 +64,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.faults import FAULT_LANES, FaultPlan
+from repro.core.instrument import SPANS, OpenSpan
 from repro.core import (
     AccessOutcome,
     AccessType,
@@ -103,6 +113,8 @@ class Request:
     _seq: int = field(default=-1, init=False, repr=False)
     _submit_step: int = field(default=0, init=False, repr=False)
     _faulted: bool = field(default=False, init=False, repr=False)
+    #: the ``engine.queued`` span, from submission to the prefill
+    _queued: Optional[OpenSpan] = field(default=None, init=False, repr=False)
 
 
 @dataclass(frozen=True)
@@ -214,11 +226,12 @@ class Engine:
         seeded categorical sampling at ``ServeConfig.temperature`` (the RNG
         key is split per call, so runs are reproducible for a fixed
         ``sample_seed``)."""
-        if self.scfg.greedy:
-            return np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        self._rng, sub = jax.random.split(self._rng)
-        temp = max(float(self.scfg.temperature), 1e-6)
-        return np.asarray(jax.random.categorical(sub, logits / temp, axis=-1), np.int32)
+        with jax.named_scope("select_tokens"):
+            if self.scfg.greedy:
+                return np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            self._rng, sub = jax.random.split(self._rng)
+            temp = max(float(self.scfg.temperature), 1e-6)
+            return np.asarray(jax.random.categorical(sub, logits / temp, axis=-1), np.int32)
 
     def _estimate_kv_bytes_per_token(self) -> int:
         itemsize = jnp.dtype(self.cfg.compute_jdtype()).itemsize
@@ -235,7 +248,8 @@ class Engine:
         req.stream_id = s.stream_id
         if req.tenant:
             self._tenants[s.stream_id] = req.tenant
-        req.submitted_s = time.perf_counter()
+        req._queued = SPANS.begin("engine.queued", s.stream_id)
+        req.submitted_s = req._queued.start_ns * 1e-9
         req._seq = self._seq
         self._seq += 1
         req._submit_step = self._step_count
@@ -348,27 +362,30 @@ class Engine:
         self.queue = [r for r in self.queue if id(r) not in dead]
         self._backoff = [e for e in self._backoff if id(e[2]) not in dead]
         heapq.heapify(self._backoff)
-        for req in victims:
-            self.table.inc_stats(
-                AccessType.FAULT, AccessOutcome.TIMEOUT_EXPIRED, req.stream_id, 1
-            )
-            self._finish(req, "timeout", "request_timeout")
+        with SPANS.span("engine.stats", records=len(victims)):
+            for req in victims:
+                self.table.inc_stats(
+                    AccessType.FAULT, AccessOutcome.TIMEOUT_EXPIRED, req.stream_id, 1
+                )
+                self._finish(req, "timeout", "request_timeout")
 
     def _admit(self) -> None:
         cap = self.scfg.max_admits_per_step
         admitted = 0
-        for slot in range(self.scfg.n_slots):
-            if self.slots[slot] is not None:
-                continue
-            # keep prefilling into this slot until something survives its
-            # own prefill (a request whose first token terminates it retires
-            # immediately and never occupies the slot)
-            while self.queue and self.slots[slot] is None:
-                if cap > 0 and admitted >= cap:
-                    return
-                req = self.queue.pop(0)
-                admitted += 1
-                self._prefill_one(req, slot)
+        with SPANS.span("engine.admit") as sp:
+            for slot in range(self.scfg.n_slots):
+                if self.slots[slot] is not None:
+                    continue
+                # keep prefilling into this slot until something survives
+                # its own prefill (a request whose first token terminates it
+                # retires immediately and never occupies the slot)
+                while self.queue and self.slots[slot] is None:
+                    if cap > 0 and admitted >= cap:
+                        break
+                    req = self.queue.pop(0)
+                    admitted += 1
+                    self._prefill_one(req, slot)
+            sp.count(admitted=admitted)
 
     def _prefill_one(self, req: Request, slot: int) -> None:
         """Prefill one request and bind it to ``slot`` — unless its prefill
@@ -377,37 +394,40 @@ class Engine:
         tokens it produced and the slot stays free (bugfix: the old path
         unconditionally entered decode, so eos-at-prefill decoded anyway and
         ``max_new_tokens=1`` retired with 2 tokens)."""
-        t0 = time.perf_counter()
-        uid = self.stats.step_begin("prefill", req.stream_id)
-        tokens = jnp.asarray(req.prompt, jnp.int32)[None]
-        logits, small = self._prefill(self.params, {"tokens": tokens})
-        nxt = int(self._select_tokens(logits)[0])
         plen = len(req.prompt)
-        req.generated.append(nxt)
-        req.prefill_s = time.perf_counter() - t0
-        req.ttft_s = time.perf_counter() - req.submitted_s
-        self.stats.step_end(uid, tokens=plen)
-        self.table.inc_stats(
-            AccessType.KV_ACC_W, AccessOutcome.MISS, req.stream_id,
-            plen * self._kv_bytes_per_token,
-        )
-        # SLO lane: submission → first token, µs (clamped to ≥1 so every
-        # prefetched request owns a nonzero TTFT cell — queries count samples
-        # by nonzero cells)
-        self.table.inc_stats(
-            AccessType.SLO, AccessOutcome.TTFT_US, req.stream_id,
-            max(int(req.ttft_s * 1e6), 1),
-        )
-        hit_eos = req.eos_id >= 0 and nxt == req.eos_id
-        if hit_eos or len(req.generated) >= req.max_new_tokens:
-            self._finish(req, "done", "request_done")
-            return
+        with SPANS.span("engine.prefill", req.stream_id, prompt_tokens=plen) as pf:
+            # the queue wait ends where the prefill starts
+            req._queued.end(pf.start_ns)
+            tokens = jnp.asarray(req.prompt, jnp.int32)[None]
+            logits, small = self._prefill(self.params, {"tokens": tokens})
+            nxt = int(self._select_tokens(logits)[0])
+            req.generated.append(nxt)
+        req.prefill_s = pf.seconds
+        req.ttft_s = (pf.end_ns - req._queued.start_ns) * 1e-9
+        with SPANS.span("engine.stats", records=1):
+            self.stats.land("prefill", pf, tokens=plen)
+            self.table.inc_stats(
+                AccessType.KV_ACC_W, AccessOutcome.MISS, req.stream_id,
+                plen * self._kv_bytes_per_token,
+            )
+            # SLO lane: submission → first token, µs (clamped to ≥1 so every
+            # prefetched request owns a nonzero TTFT cell — queries count
+            # samples by nonzero cells)
+            self.table.inc_stats(
+                AccessType.SLO, AccessOutcome.TTFT_US, req.stream_id,
+                max(int(req.ttft_s * 1e6), 1),
+            )
+            hit_eos = req.eos_id >= 0 and nxt == req.eos_id
+            if hit_eos or len(req.generated) >= req.max_new_tokens:
+                self._finish(req, "done", "request_done")
+                return
         # place this sequence's prompt cache into the batched slot buffers
-        one = init_cache(self.cfg, 1, self.scfg.max_len, dtype=self.cfg.compute_jdtype())
-        one = transplant(one, small)
-        self.cache = jax.tree_util.tree_map(
-            lambda big, o: _write_slot(big, o, slot), self.cache, one
-        )
+        with SPANS.span("engine.place", req.stream_id), jax.named_scope("write_slot"):
+            one = init_cache(self.cfg, 1, self.scfg.max_len, dtype=self.cfg.compute_jdtype())
+            one = transplant(one, small)
+            self.cache = jax.tree_util.tree_map(
+                lambda big, o: _write_slot(big, o, slot), self.cache, one
+            )
         self.pos[slot] = plen
         self.last_token[slot] = nxt
         self.slots[slot] = req
@@ -424,14 +444,13 @@ class Engine:
                 return b
         return self.scfg.n_slots
 
-    def _decode_active(self, active: List[int]):
-        """One decode step over the smallest bucket covering the active
-        slots.  ``bucket == n_slots`` is the literal unsliced path (the
-        pre-bucket behavior, bit-for-bit); a smaller bucket slices the cache
-        leaves down to the bucket, decodes, and writes the advanced rows
-        back.  Decode is row-independent, so active rows see identical math
-        either way."""
-        bucket = self._bucket_for(max(active) + 1)
+    def _decode_active(self, bucket: int):
+        """One decode step over ``bucket`` slots, the smallest bucket
+        covering the active ones.  ``bucket == n_slots`` is the literal
+        unsliced path (the pre-bucket behavior, bit-for-bit); a smaller
+        bucket slices the cache leaves down to the bucket, decodes, and
+        writes the advanced rows back.  Decode is row-independent, so active
+        rows see identical math either way."""
         if bucket == self.scfg.n_slots:
             tokens = jnp.asarray(self.last_token)
             pos = jnp.asarray(self.pos)
@@ -457,47 +476,51 @@ class Engine:
 
     def step(self) -> int:
         """One engine iteration.  Returns #active slots advanced."""
-        self._step_count += 1
-        plan = self.scfg.fault_plan
-        if self._backoff and plan is not None:
-            self._release_backoff(plan)
-        if plan is not None or any(
-            r is not None and r.deadline_steps > 0
-            for r in (*self.queue, *self.slots)
-        ):
-            self._expire_deadlines(plan)
-        self._admit()
-        active = self._active()
-        if not active:
-            return 0
-        t0 = time.perf_counter()
-        uids = {i: self.stats.step_begin("decode", self.slots[i].stream_id) for i in active}
-        nxt = self._select_tokens(self._decode_active(active))
-        dt = time.perf_counter() - t0
-        # One vectorized ingest for the whole decode batch: every active
-        # slot wrote one token's KV bytes on its own stream this step.
-        # Cumulative lane only — same stores the seed's inc_stats loop fed.
-        sids = np.fromiter((self.slots[i].stream_id for i in active), dtype=np.int64, count=len(active))
-        self.table.record_batch(
-            np.full(len(active), int(AccessType.KV_ACC_W), dtype=np.int64),
-            np.full(len(active), int(AccessOutcome.MISS), dtype=np.int64),
-            sids,
-            np.full(len(active), self._kv_bytes_per_token, dtype=np.uint64),
-            pw=False,
-            clean=False,
-        )
-        for i in active:
-            req = self.slots[i]
-            req.decode_s += dt / len(active)  # fair-share attribution
-            self.stats.step_end(uids[i], tokens=1)
-            req.generated.append(int(nxt[i]))
-            self.pos[i] += 1
-            self.last_token[i] = nxt[i]
-            hit_eos = req.eos_id >= 0 and int(nxt[i]) == req.eos_id
-            if hit_eos or len(req.generated) >= req.max_new_tokens or self.pos[i] >= self.scfg.max_len - 1:
-                self.slots[i] = None
-                self._finish(req, "done", "request_done")
-        return len(active)
+        with SPANS.span("engine.step", queued=len(self.queue)) as sp:
+            self._step_count += 1
+            plan = self.scfg.fault_plan
+            if self._backoff and plan is not None:
+                self._release_backoff(plan)
+            if plan is not None or any(
+                r is not None and r.deadline_steps > 0
+                for r in (*self.queue, *self.slots)
+            ):
+                self._expire_deadlines(plan)
+            self._admit()
+            active = self._active()
+            sp.count(active=len(active))
+            if not active:
+                return 0
+            bucket = self._bucket_for(max(active) + 1)
+            with SPANS.span("engine.decode", active=len(active), bucket=bucket) as dec:
+                nxt = self._select_tokens(self._decode_active(bucket))
+            with SPANS.span("engine.stats", records=len(active)):
+                # One vectorized ingest for the whole decode batch: every active
+                # slot wrote one token's KV bytes on its own stream this step.
+                # Cumulative lane only — same stores the seed's inc_stats loop fed.
+                sids = np.fromiter((self.slots[i].stream_id for i in active), dtype=np.int64,
+                                   count=len(active))
+                self.table.record_batch(
+                    np.full(len(active), int(AccessType.KV_ACC_W), dtype=np.int64),
+                    np.full(len(active), int(AccessOutcome.MISS), dtype=np.int64),
+                    sids,
+                    np.full(len(active), self._kv_bytes_per_token, dtype=np.uint64),
+                    pw=False,
+                    clean=False,
+                )
+                for i in active:
+                    req = self.slots[i]
+                    req.decode_s += dec.seconds / len(active)  # fair-share attribution
+                    self.stats.land("decode", dec, req.stream_id, tokens=1)
+                    req.generated.append(int(nxt[i]))
+                    self.pos[i] += 1
+                    self.last_token[i] = nxt[i]
+                    hit_eos = req.eos_id >= 0 and int(nxt[i]) == req.eos_id
+                    if (hit_eos or len(req.generated) >= req.max_new_tokens
+                            or self.pos[i] >= self.scfg.max_len - 1):
+                        self.slots[i] = None
+                        self._finish(req, "done", "request_done")
+            return len(active)
 
     def _finish(self, req: Request, status: str, event: str) -> None:
         """The one terminal path every disposition funnels through (done /
@@ -512,48 +535,53 @@ class Engine:
           simulator's kernel-exit and the trainer's summary,
         * bounded memory: fold this stream's step records into its
           aggregate (:meth:`StreamStats.retire_stream`).
+
+        All of it is the request's ``engine.finish`` span.
         """
-        req.done = True
-        req.status = status
-        sid = req.stream_id
-        self.table.inc_stats(
-            AccessType.SLO, AccessOutcome.LATENCY_US, sid,
-            max(int((time.perf_counter() - req.submitted_s) * 1e6), 1),
-        )
-        if status == "done":
-            if req.generated:
-                self.table.inc_stats(
-                    AccessType.SLO, AccessOutcome.TOKENS_OUT, sid, len(req.generated)
-                )
-            if req._faulted:
-                # completed despite shedding/backoff: graceful degradation worked
-                self.table.inc_stats(
-                    AccessType.FAULT, AccessOutcome.RECOVERED, sid, 1
-                )
-        self._status_counts[status] = self._status_counts.get(status, 0) + 1
-        fields: Dict[str, Any] = {
-            "name": req.name,
-            "tokens_out": len(req.generated),
-            "prefill_s": req.prefill_s,
-            "decode_s": req.decode_s,
-            "retries": req.retries,
-            "status": status,
-        }
-        if req.tenant:
-            fields["tenant"] = req.tenant
-        report = stream_report(
-            self.frame,
-            sid,
-            source="serve",
-            event=event,
-            cache_name="Serve_stats",
-            fields=fields,
-        )
-        req.exit_report = render_text(report)
-        self.stats.retire_stream(sid)
-        self._retired.append(req)
-        for sink in self.sinks:
-            sink.emit(report)
+        with SPANS.span("engine.finish", req.stream_id) as fin:
+            req.done = True
+            req.status = status
+            sid = req.stream_id
+            # a request that leaves without a prefill ends its queue wait here
+            req._queued.end(fin.start_ns)
+            self.table.inc_stats(
+                AccessType.SLO, AccessOutcome.LATENCY_US, sid,
+                max((fin.start_ns - req._queued.start_ns) // 1000, 1),
+            )
+            if status == "done":
+                if req.generated:
+                    self.table.inc_stats(
+                        AccessType.SLO, AccessOutcome.TOKENS_OUT, sid, len(req.generated)
+                    )
+                if req._faulted:
+                    # completed despite shedding/backoff: graceful degradation worked
+                    self.table.inc_stats(
+                        AccessType.FAULT, AccessOutcome.RECOVERED, sid, 1
+                    )
+            self._status_counts[status] = self._status_counts.get(status, 0) + 1
+            fields: Dict[str, Any] = {
+                "name": req.name,
+                "tokens_out": len(req.generated),
+                "prefill_s": req.prefill_s,
+                "decode_s": req.decode_s,
+                "retries": req.retries,
+                "status": status,
+            }
+            if req.tenant:
+                fields["tenant"] = req.tenant
+            report = stream_report(
+                self.frame,
+                sid,
+                source="serve",
+                event=event,
+                cache_name="Serve_stats",
+                fields=fields,
+            )
+            req.exit_report = render_text(report)
+            self.stats.retire_stream(sid)
+            self._retired.append(req)
+            for sink in self.sinks:
+                sink.emit(report)
 
     def drain_retired(self) -> List[Request]:
         """Hand over (and forget) every request retired since the last drain.

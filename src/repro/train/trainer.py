@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core import ReportSink, StepCost, StreamStats
+from repro.core.instrument import SPANS
 from repro.models import forward, init_params, model_defs
 from repro.optim import (
     AdamWConfig,
@@ -194,9 +195,13 @@ class Trainer:
         return init_train_state(self.cfg, self.tcfg)
 
     def run(self, params, opt_state, num_steps: int):
+        """``num_steps`` steps, then the reports.  Program spans:
+        ``train.batch`` (the next batch), ``train.step`` (the step and its
+        sync, the profiler's step annotation) and ``train.report``."""
         history = []
         for _ in range(num_steps):
-            batch = next(self.data_iter)
+            with SPANS.span("train.batch", self.train_stream):
+                batch = next(self.data_iter)
             if self._compiled_step is None:
                 # compile once; the stream's per-step cost comes from the
                 # same executable that runs
@@ -205,15 +210,16 @@ class Trainer:
                 self._step_cost = StepCost(
                     s.flops_per_device, s.hbm_bytes_per_device, s.collective_wire_bytes_per_device
                 )
-            uid = self.stats.step_begin("train_step", self.train_stream)
-            params, opt_state, metrics = self._compiled_step(params, opt_state, batch)
-            metrics = jax.tree_util.tree_map(lambda x: x.block_until_ready(), metrics)
-            self.stats.step_end(
-                uid,
-                tokens=int(metrics["tokens"]),
-                cost=self._step_cost,
-                loss=float(metrics["loss"]),
-            )
+            with SPANS.span("train.step", self.train_stream, step_num=self.step):
+                uid = self.stats.step_begin("train_step", self.train_stream)
+                params, opt_state, metrics = self._compiled_step(params, opt_state, batch)
+                metrics = jax.tree_util.tree_map(lambda x: x.block_until_ready(), metrics)
+                self.stats.step_end(
+                    uid,
+                    tokens=int(metrics["tokens"]),
+                    cost=self._step_cost,
+                    loss=float(metrics["loss"]),
+                )
             self.step += 1
             history.append({k: float(v) for k, v in metrics.items()})
             if self.ckpt is not None and self.ckpt_every and self.step % self.ckpt_every == 0:
@@ -222,7 +228,8 @@ class Trainer:
                 ebatch = next(self.eval_iter)
                 with self.stats.step("eval_step", self.eval_stream):
                     self.eval_fn(params, ebatch)
-        self.emit_reports()
+        with SPANS.span("train.report"):
+            self.emit_reports()
         return params, opt_state, history
 
     def frame(self):
