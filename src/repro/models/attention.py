@@ -39,6 +39,27 @@ __all__ = [
 ]
 
 
+# ================================================================ decode caches
+def _write_row(buf: jax.Array, row: jax.Array, pos: jax.Array, layer) -> jax.Array:
+    """Scatter each sequence's new ``row`` into ``buf`` at its ``pos``.
+
+    ``buf`` is one layer's ``(B, S, ...)`` cache, or with ``layer`` the
+    ``(L, B, S, ...)`` stack of every layer's: the scatter then touches only
+    ``B`` rows of the stack, in place where the stack is a donated or
+    loop-carried buffer."""
+    idx = (jnp.arange(row.shape[0]), pos)
+    if layer is not None:
+        idx = (layer,) + idx
+    return buf.at[idx].set(row.astype(buf.dtype))
+
+
+def _layer_of(buf: jax.Array, layer) -> jax.Array:
+    """Layer ``layer`` of a stacked cache (``buf`` itself without a layer)."""
+    if layer is None:
+        return buf
+    return jax.lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
+
+
 # =========================================================================== GQA
 def gqa_defs(cfg: ModelConfig) -> Dict[str, ParamDef]:
     hd = cfg.resolved_head_dim
@@ -105,8 +126,14 @@ def gqa_decode(
     cfg: ModelConfig,
     cache: Dict[str, jax.Array],
     pos: jax.Array,  # (B,) write/read position of the new token
+    layer: Optional[jax.Array] = None,
 ):
-    """One decode step: write K/V at ``pos``, attend over the valid prefix."""
+    """One decode step: write K/V at ``pos``, attend over the valid prefix.
+
+    With ``layer``, ``cache`` holds every layer's K/V stacked on a leading
+    axis: the new rows are written into layer ``layer`` in place and its
+    prefix is read from the stack, so no slab of one layer is copied out and
+    back (see :func:`_write_row`)."""
     dtype = x.dtype
     q = jnp.einsum("bd,dhk->bhk", x, params["wq"].astype(dtype))
     k = jnp.einsum("bd,dhk->bhk", x, params["wk"].astype(dtype))
@@ -118,10 +145,9 @@ def gqa_decode(
     if cfg.use_rope:
         q = rope(q[:, None], pos[:, None], cfg.rope_theta)[:, 0]
         k = rope(k[:, None], pos[:, None], cfg.rope_theta)[:, 0]
-    B = x.shape[0]
-    k_cache = cache["k"].at[jnp.arange(B), pos].set(k.astype(cache["k"].dtype))
-    v_cache = cache["v"].at[jnp.arange(B), pos].set(v.astype(cache["v"].dtype))
-    o = ops.decode_attention(q, k_cache, v_cache, pos + 1)
+    k_cache = _write_row(cache["k"], k, pos, layer)
+    v_cache = _write_row(cache["v"], v, pos, layer)
+    o = ops.decode_attention(q, _layer_of(k_cache, layer), _layer_of(v_cache, layer), pos + 1)
     out = jnp.einsum("bhk,hkd->bd", o, params["wo"].astype(dtype))
     return out, {"k": k_cache, "v": v_cache}
 
@@ -211,11 +237,13 @@ def mla_decode(
     cfg: ModelConfig,
     cache: Dict[str, jax.Array],
     pos: jax.Array,  # (B,)
+    layer: Optional[jax.Array] = None,
 ):
     """Weight-absorbed MLA decode: attention runs in the compressed space.
 
     q_c = q_nope @ w_uk  → score = q_c·c + q_rope·k_rope over the latent
-    cache; the weighted latent sum is expanded through w_uv once.
+    cache; the weighted latent sum is expanded through w_uv once.  A stacked
+    cache and its ``layer`` are read and written as in :func:`gqa_decode`.
     """
     m = cfg.mla
     dtype = x.dtype
@@ -224,8 +252,8 @@ def mla_decode(
     c_new, k_rope_new = _mla_ckv(params, x[:, None], cfg, pos[:, None])
     ckv_new = jnp.concatenate([c_new, k_rope_new], axis=-1)[:, 0]
 
-    B = x.shape[0]
-    ckv = cache["ckv"].at[jnp.arange(B), pos].set(ckv_new.astype(cache["ckv"].dtype))
+    ckv_stack = _write_row(cache["ckv"], ckv_new, pos, layer)
+    ckv = _layer_of(ckv_stack, layer)
     c_cache, r_cache = ckv[..., : m.kv_lora_rank], ckv[..., m.kv_lora_rank :]
 
     q_c = jnp.einsum("bhk,rhk->bhr", q_nope, params["w_uk"].astype(dtype))
@@ -239,7 +267,7 @@ def mla_decode(
     o_c = jnp.einsum("bhs,bsr->bhr", p, c_cache.astype(dtype))
     o = jnp.einsum("bhr,rhv->bhv", o_c, params["w_uv"].astype(dtype))
     out = jnp.einsum("bhv,hvd->bd", o, params["wo"].astype(dtype))
-    return out, {"ckv": ckv}
+    return out, {"ckv": ckv_stack}
 
 
 # ==================================================================== cross-attn

@@ -374,20 +374,36 @@ def decode_step(
         pass  # scaling applied inside embed_apply
     n_prefix, period, repeats = num_layers_in_stack(cfg)
 
-    def one_layer(lp, lc, x):
+    def take(tree, layer):
+        return jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False), tree
+        )
+
+    def one_layer(lp, lc, x, layer=None):
+        """One decoder layer.  With ``layer``, ``lc`` is the stacked cache of
+        every repeat and ``layer`` the index of this one: attention writes
+        its new row into the stack in place and reads its prefix there; an
+        SSM mixer, whose whole state changes every step, is taken out and
+        written back; cross caches are only read."""
         h = rmsnorm(lp["ln1"], x, cfg.rms_eps)
         if "attn" in lp:
-            if cfg.mla is not None:
-                out, new_mixer = attn_mod.mla_decode(lp["attn"], h, cfg, lc["mixer"], pos)
-            else:
-                out, new_mixer = attn_mod.gqa_decode(lp["attn"], h, cfg, lc["mixer"], pos)
-        else:
+            decode = attn_mod.mla_decode if cfg.mla is not None else attn_mod.gqa_decode
+            out, new_mixer = decode(lp["attn"], h, cfg, lc["mixer"], pos, layer)
+        elif layer is None:
             out, new_mixer = mamba_mod.mamba_decode(lp["ssm"], h, cfg, lc["mixer"])
+        else:
+            out, state = mamba_mod.mamba_decode(lp["ssm"], h, cfg, take(lc["mixer"], layer))
+            new_mixer = jax.tree_util.tree_map(
+                lambda buf, upd: jax.lax.dynamic_update_index_in_dim(buf, upd.astype(buf.dtype), layer, 0),
+                lc["mixer"],
+                state,
+            )
         x = x + out
         new_cache = {"mixer": new_mixer}
         if "cross" in lp and "cross" in lc:
             hx = rmsnorm(lp["ln_x"], x, cfg.rms_eps)
-            x = x + attn_mod.cross_attn_apply(lp["cross"], hx, cfg, lc["cross"])
+            kv = lc["cross"] if layer is None else take(lc["cross"], layer)
+            x = x + attn_mod.cross_attn_apply(lp["cross"], hx, cfg, kv)
             new_cache["cross"] = lc["cross"]
         if "moe" in lp:
             h2 = rmsnorm(lp["ln2"], x[:, None], cfg.rms_eps)
@@ -414,29 +430,16 @@ def decode_step(
 
         x, new_blocks = jax.lax.scan(body, x, (params["blocks"], cache["blocks"]))
     else:
-        # in-place loop: the stacked cache is the carry, each iteration
-        # dynamic-update-slices its layer back — XLA keeps ONE cache buffer
+        # in-place loop: the stacked cache is the carry and each layer
+        # updates its part of it where it lies — XLA keeps ONE cache buffer
         # (aliased with the donated input) instead of scan's xs/ys pair.
         def fbody(r, carry):
             x, blocks_cache = carry
-            lp = jax.tree_util.tree_map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, r, 0, keepdims=False),
-                params["blocks"],
-            )
-            lc = jax.tree_util.tree_map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, r, 0, keepdims=False),
-                blocks_cache,
-            )
+            lp = take(params["blocks"], r)
             new_c = {}
             for p in range(period):
-                x, c = one_layer(lp[f"pos_{p}"], lc[f"pos_{p}"], x)
-                new_c[f"pos_{p}"] = c
-            blocks_cache = jax.tree_util.tree_map(
-                lambda buf, upd: jax.lax.dynamic_update_index_in_dim(buf, upd.astype(buf.dtype), r, 0),
-                blocks_cache,
-                new_c,
-            )
-            return (x, blocks_cache)
+                x, new_c[f"pos_{p}"] = one_layer(lp[f"pos_{p}"], blocks_cache[f"pos_{p}"], x, r)
+            return (x, new_c)
 
         x, new_blocks = jax.lax.fori_loop(0, repeats, fbody, (x, cache["blocks"]))
     x = rmsnorm(params["final_norm"], x, cfg.rms_eps)

@@ -137,25 +137,61 @@ def test_decode_matches_forward(arch, arch_state):
     assert jax.tree_util.tree_structure(new_cache) == jax.tree_util.tree_structure(cache)
 
 
-def test_decode_loop_variants_agree(arch_state):
+@pytest.mark.parametrize("arch", DECODE_ARCHS)
+def test_decode_loop_variants_agree(arch, arch_state):
+    """The in-place layer loop, which writes attention rows into the stacked
+    cache where they lie, matches ``lax.scan`` over 4 steps: the same logits
+    and bit-identical caches, rows at different depths, one reaching the
+    cache's last position."""
     from dataclasses import replace
 
-    cfg, params = arch_state("deepseek-7b")
-    B, S = 2, 16
-    toks = jax.random.randint(KEY, (B, S + 1), 0, cfg.vocab_size)
-    _, small = jax.jit(lambda p, b: prefill(cfg, p, b))(params, {"tokens": toks[:, :S]})
-    big = init_cache(cfg, B, 48)
-    cache = transplant(big, small)
-    pos = jnp.full((B,), S, jnp.int32)
+    cfg, params = arch_state(arch)
+    if cfg.moe is not None:  # drop-free, as in test_decode_matches_forward
+        cfg = replace(cfg, moe=replace(cfg.moe, capacity_factor=float(cfg.moe.n_experts)))
+    B, S, steps = 3, 16, 4
+    vis = cfg.vision_tokens or 0
+    max_len = 48 + vis
+    toks = jax.random.randint(KEY, (B, S + steps), 0, cfg.vocab_size)
+    batch = make_batch(cfg, B, S)
+    batch["tokens"] = toks[:, :S]
+    _, small = jax.jit(lambda p, b: prefill(cfg, p, b))(params, batch)
+    cache = transplant(init_cache(cfg, B, max_len, enc_len=64 if cfg.encdec else 0), small)
+    pos0 = jnp.asarray([S + vis, S + vis + 5, max_len - steps], jnp.int32)
     outs = {}
     for loop in ("inplace", "scan"):
         c2 = replace(cfg, decode_loop=loop)
-        outs[loop], _ = jax.jit(lambda p, c, t, q: decode_step(c2, p, c, t, q))(
-            params, cache, toks[:, S], pos
-        )
-    np.testing.assert_allclose(
-        np.asarray(outs["inplace"]), np.asarray(outs["scan"]), atol=1e-5, rtol=1e-5
-    )
+        step = jax.jit(lambda p, c, t, q: decode_step(c2, p, c, t, q))
+        c, logits = cache, []
+        for i in range(steps):
+            out, c = step(params, c, toks[:, S + i], pos0 + i)
+            logits.append(out)
+        outs[loop] = (logits, c)
+    (lg_in, c_in), (lg_sc, c_sc) = outs["inplace"], outs["scan"]
+    for a, b in zip(lg_in, lg_sc):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-5)
+    assert jax.tree_util.tree_structure(c_in) == jax.tree_util.tree_structure(c_sc)
+    for a, b in zip(jax.tree_util.tree_leaves(c_in), jax.tree_util.tree_leaves(c_sc)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_inplace_decode_writes_no_layer_slab(arch_state):
+    """The lowered in-place decode loop writes attention rows into the
+    stacked cache: no ``dynamic_update_slice`` puts back a whole layer's
+    ``(B, S, Hkv, D)`` slab."""
+    import re
+
+    cfg, params = arch_state("deepseek-7b")
+    n_slots, max_len = 3, 40
+    cache = jax.eval_shape(lambda: init_cache(cfg, n_slots, max_len))
+    vec = jax.ShapeDtypeStruct((n_slots,), jnp.int32)
+    text = jax.jit(lambda p, c, t, q: decode_step(cfg, p, c, t, q)).lower(
+        params, cache, vec, vec
+    ).as_text()
+    assert "stablehlo.while" in text
+    slab = f"tensor<1x{n_slots}x{max_len}x{cfg.n_kv_heads}x{cfg.resolved_head_dim}x"
+    updates = re.findall(r"stablehlo\.dynamic_update_slice .*? : \(tensor<[^>]*>, (tensor<[^>]*>)", text)
+    assert not [u for u in updates if u.startswith(slab)], updates
 
 
 def test_param_counts_match_pool_spec():

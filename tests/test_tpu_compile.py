@@ -127,8 +127,12 @@ def test_deepseek_7b_layer_prefill(one_chip, chip_dispatch):
 
 
 def test_deepseek_7b_layer_decode_step(one_chip):
-    """Decode attention is plain jnp (bandwidth-bound), so no kernel here."""
-    cfg = _deepseek(1)
+    """Decode attention is plain jnp (bandwidth-bound), so no kernel here.
+
+    Two layers, so the in-place layer loop really indexes its stacked
+    cache: no op of the compiled step outputs one layer's whole K or V slab,
+    which would be a copy of it out of the stack and back."""
+    cfg = _deepseek(2)
     n_slots, max_len = 4, 1024
     params = _on(one_chip, abstract_params(model_defs(cfg), cfg.param_jdtype()))
     cache = _on(one_chip, jax.eval_shape(
@@ -138,6 +142,9 @@ def test_deepseek_7b_layer_decode_step(one_chip):
     step = jax.jit(partial(decode_step, cfg), donate_argnums=(1,))
     compiled = step.lower(params, cache, vec, vec).compile()
     _fits_one_chip(compiled)
+    slab = f"bf16[{n_slots},{max_len},{cfg.n_kv_heads},{cfg.resolved_head_dim}]"
+    copies = [line.strip() for line in compiled.as_text().splitlines() if f"= {slab}" in line]
+    assert not copies, copies[:2]
 
 
 def test_mamba2_130m_train_step(one_chip, chip_dispatch):
