@@ -42,7 +42,8 @@ def main(argv=None) -> int:
     cell = R.find_cell(manifest, args.workload)
     conf = R.load_config(cell["config"])
     mix = traffic.load_mix(cell["traffic"])
-    sc = ServeCell(conf, mix, R.load_reference(conf), args.seed, args.seconds)
+    family = R.load_reference(conf)
+    sc = ServeCell(conf, mix, family, args.seed, args.seconds)
     sc.setup()
     period = mix["burst"]["period_s"]
     for rate in (float(r) for r in args.rates.split(",")):
@@ -51,8 +52,8 @@ def main(argv=None) -> int:
         sc.prompts = traffic.prompt_tokens(args.seed, sc.arrivals, conf["model"]["vocab_size"])
         w = sc.window()
         run = RunRecord(cell=cell, config=conf, mix=m, seconds=args.seconds, setup_s=0.0,
-                        peaks=peaks_for(device.device_kind), t0=w.t0, t_end=w.t_end,
-                        requests=w.requests, steps=w.steps)
+                        peaks=peaks_for(device.device_kind), family=family, t0=w.t0,
+                        t_end=w.t_end, requests=w.requests, steps=w.steps)
         at_periods = []
         for k in range(int(args.seconds // period) + 1):
             t = w.t0 + k * period

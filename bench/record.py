@@ -2,7 +2,10 @@
 
 A reader is a module with ``read(run: RunRecord) -> float | None``; it
 returns ``None`` where the run holds nothing for it to read, and the
-harness then leaves that metric out of the result line.
+harness then leaves that metric out of the result line.  A reader that
+calls the configuration's family module (``run.family``) lists the
+functions it calls in ``NEEDS``; a family that lacks one is an error
+before the run.
 """
 
 from __future__ import annotations
@@ -19,6 +22,9 @@ class RunRecord:
     seconds: float
     setup_s: float
     peaks: object  # bench.peaks.Peaks
+    #: the configuration's family module, ``bench/reference/<family>.py``:
+    #: the readers count FLOPs and bytes with its functions
+    family: object = None
     #: the window on the host clock (``time.perf_counter`` seconds)
     t0: float = 0.0
     t_end: float = 0.0

@@ -16,11 +16,19 @@ of 0.  The reference reads the same arrays, upcast to float32.
 operands of every matrix product rounded to float8 e4m3, each tensor
 scaled to the format's range first (per tensor for weights, per row for
 activations), accumulated in float32.
+
+This module is also the one place that reads the family's published keys
+for the harness: ``program_config`` maps the file onto the program's
+``ModelConfig`` (the only function here that imports the program), and
+``cache_bytes_per_token``, ``prefill_flops``, ``decode_flops``,
+``attention_flops``, ``attention_bytes`` and ``attention_calls`` count from
+those keys alone what the checks and the metric readers compare.
 """
 
 from __future__ import annotations
 
 import functools
+from dataclasses import replace
 from typing import Dict, List, Sequence
 
 import jax
@@ -30,6 +38,74 @@ import numpy as np
 HIGHEST = jax.lax.Precision.HIGHEST
 _FP8 = jnp.float8_e4m3fn
 _FP8_MAX = 448.0
+
+
+def program_config(conf: Dict):
+    """The program's model configuration with every size the file states."""
+    from repro.configs import get_config
+
+    m = conf["model"]
+    return replace(
+        get_config(conf["arch"]),
+        n_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
+        n_heads=m["num_attention_heads"], n_kv_heads=m["num_key_value_heads"],
+        head_dim=m.get("head_dim", 0), d_ff=m["intermediate_size"],
+        vocab_size=m["vocab_size"], rope_theta=float(m["rope_theta"]),
+        rms_eps=float(m["rms_norm_eps"]), param_dtype=m["torch_dtype"],
+        compute_dtype=m["torch_dtype"],
+    )
+
+
+def _head_dim(m: Dict) -> int:
+    return m.get("head_dim") or m["hidden_size"] // m["num_attention_heads"]
+
+
+def cache_bytes_per_token(m: Dict) -> int:
+    """K and V of every layer for one token, in the served dtype."""
+    return (2 * m["num_key_value_heads"] * _head_dim(m) * m["num_hidden_layers"]
+            * jnp.dtype(m["torch_dtype"]).itemsize)
+
+
+def layer_matmul_params(m: Dict) -> int:
+    d, H, Hkv = m["hidden_size"], m["num_attention_heads"], m["num_key_value_heads"]
+    D = _head_dim(m)
+    return d * H * D + 2 * d * Hkv * D + H * D * d + 3 * d * m["intermediate_size"]
+
+
+def prefill_flops(m: Dict, S: int) -> int:
+    """Model FLOPs of an ``S``-token prefill: the matrix products of every
+    layer, causal attention over the positions each token sees, and the
+    output head at the last position only."""
+    L, H = m["num_hidden_layers"], m["num_attention_heads"]
+    D = _head_dim(m)
+    return (2 * layer_matmul_params(m) * L * S + 2 * H * D * S * (S + 1) * L
+            + 2 * m["hidden_size"] * m["vocab_size"])
+
+
+def decode_flops(m: Dict, pos: int) -> int:
+    """One token at position ``pos``, attending to ``pos + 1`` positions."""
+    L, H = m["num_hidden_layers"], m["num_attention_heads"]
+    D = _head_dim(m)
+    return (2 * layer_matmul_params(m) * L + 4 * H * D * (pos + 1) * L
+            + 2 * m["hidden_size"] * m["vocab_size"])
+
+
+def attention_flops(m: Dict, S: int) -> int:
+    """One attention kernel call at length ``S``: QK^T and PV over the
+    causal triangle, diagonal included."""
+    return 2 * m["num_attention_heads"] * _head_dim(m) * S * (S + 1)
+
+
+def attention_bytes(m: Dict, S: int, itemsize: int = 2) -> int:
+    """One attention kernel call at length ``S``: Q, K and V read once, O
+    written once."""
+    H, Hkv = m["num_attention_heads"], m["num_key_value_heads"]
+    return itemsize * S * _head_dim(m) * (2 * H + 2 * Hkv)
+
+
+def attention_calls(m: Dict) -> int:
+    """Attention kernel calls per prefill: one per layer."""
+    return m["num_hidden_layers"]
 
 
 def make_weights(m: Dict, seed_key: jax.Array):

@@ -25,12 +25,19 @@ stacked under ``blocks.pos_0``; a norm's weight stored as ``1 + scale``).
 operands of every matrix product rounded to float8 e4m3 after scaling to
 its range (per tensor for weights, per row for activations), and the
 gradients flowing back into them rounded to e5m2 the same way.
+
+This module is also the one place that reads the family's published keys
+for the harness: ``program_config`` maps the file onto the program's
+``ModelConfig`` (the only function here that imports the program), and
+``train_flops_per_token`` counts from those keys alone what
+``mfu_pct.train`` reads.
 """
 
 from __future__ import annotations
 
 import functools
 import math
+from dataclasses import replace
 from typing import Dict, List, Tuple
 
 import jax
@@ -40,6 +47,48 @@ import numpy as np
 HIGHEST = jax.lax.Precision.HIGHEST
 _FP8 = jnp.float8_e4m3fn
 _FP8_MAX = 448.0
+
+
+def program_config(conf: Dict):
+    """The program's model configuration with every size the file states."""
+    from repro.configs import SSMConfig, get_config
+
+    m = conf["model"]
+    return replace(
+        get_config(conf["arch"]),
+        n_layers=m["n_layer"], d_model=m["d_model"], vocab_size=m["vocab_size"],
+        rms_eps=float(m["rms_norm_eps"]),
+        ssm=SSMConfig(d_state=m["d_state"], expand=m["expand"], head_dim=m["headdim"],
+                      n_groups=m["ngroups"], conv_width=m["d_conv"], chunk=m["chunk_size"],
+                      dt_min=m["dt_min"], dt_max=m["dt_max"]),
+        param_dtype=conf["train"]["param_dtype"], compute_dtype=conf["train"]["compute_dtype"],
+        remat=conf["train"]["remat"],
+    )
+
+
+def matmul_params(m: Dict) -> int:
+    """The projections of every Mamba-2 block and the tied output head."""
+    d, P, N, G = m["d_model"], m["headdim"], m["d_state"], m["ngroups"]
+    d_in = m["expand"] * d
+    H = d_in // P
+    per_layer = d * (2 * d_in + 2 * G * N + H) + d_in * d
+    return per_layer * m["n_layer"] + m["vocab_size"] * d
+
+
+def ssd_flops_per_token(m: Dict) -> float:
+    """Forward, per token, all layers: C.B over the causal pairs of its
+    chunk, their weighted sum of x, the chunk state's update and readout."""
+    d, P, N, G, Q = m["d_model"], m["headdim"], m["d_state"], m["ngroups"], m["chunk_size"]
+    H = m["expand"] * d // P
+    pairs = (Q + 1) / 2
+    return m["n_layer"] * (2 * pairs * N * G + 2 * pairs * P * H + 4 * N * P * H)
+
+
+def train_flops_per_token(m: Dict) -> float:
+    """Training's model FLOPs per token: 6 x the matrix-product parameters
+    plus 3 x the SSD layer's own forward FLOPs in its chunked form;
+    recomputation does not count."""
+    return 6 * matmul_params(m) + 3 * ssd_flops_per_token(m)
 
 
 def sizes(m: Dict) -> Dict[str, int]:

@@ -69,6 +69,8 @@ def load_config(name: str) -> Dict:
 
 
 def load_reference(conf: Dict):
+    """The configuration's family module, ``reference/<family>.py``: the
+    plain reference and the one place that reads the family's keys."""
     return load_module(BENCH_DIR / "reference" / f"{conf['reference']}.py")
 
 
@@ -78,8 +80,24 @@ def metrics_for(manifest: Dict, cell: str, trace: bool) -> List[Dict]:
     return [m for m in group if cell in m.get("workloads", [cell])]
 
 
+def load_metric(name: str):
+    return load_module(BENCH_DIR / "metrics" / f"{name}.py")
+
+
 def read_metric(name: str, run: RunRecord) -> Optional[float]:
-    return load_module(BENCH_DIR / "metrics" / f"{name}.py").read(run)
+    return load_metric(name).read(run)
+
+
+def check_family(family, kind: str, metrics: List[Dict]) -> None:
+    """Raise, naming the module and each function, where the family module
+    lacks a function that a cell of ``kind`` or one of ``metrics`` calls."""
+    needs = [(f, f"{kind} cells") for f in cell_class(kind).NEEDS]
+    for m in metrics:
+        needs += [(f, m["name"]) for f in getattr(load_metric(m["name"]), "NEEDS", ())]
+    missing = [f"{f} (for {who})" for f, who in needs if not callable(getattr(family, f, None))]
+    if missing:
+        where = getattr(family, "__file__", None) or family.__name__
+        raise AttributeError(f"family module {where} lacks " + ", ".join(missing))
 
 
 def cell_class(kind: str):
@@ -141,7 +159,10 @@ def run_cell(cell: Dict, conf: Dict, mix: Dict, metrics: List[Dict], seed: int,
     """Set up, measure, check and reduce one run; returns the result object.
     With ``trace`` the profiler writes to ``trace_dir``, which is kept, or
     else to a temporary directory, which is removed.  ``on_record`` sees the
-    run's record once the metrics are read."""
+    run's record once the metrics are read.  A family module that lacks a
+    function the cell or a metric calls is an error before anything else."""
+    family = load_reference(conf)
+    check_family(family, conf["kind"], metrics)
     import jax
 
     devices = tpu_devices(cell["chips"]) if require_chip else jax.devices()
@@ -161,7 +182,7 @@ def run_cell(cell: Dict, conf: Dict, mix: Dict, metrics: List[Dict], seed: int,
 
     jax.monitoring.register_event_duration_secs_listener(on_event)
 
-    sc = cell_class(conf["kind"])(conf, mix, load_reference(conf), seed, seconds)
+    sc = cell_class(conf["kind"])(conf, mix, family, seed, seconds)
     sc.setup()
     keep_trace = trace_dir is not None
     if trace and not keep_trace:
@@ -188,7 +209,7 @@ def run_cell(cell: Dict, conf: Dict, mix: Dict, metrics: List[Dict], seed: int,
     sc.log_window(w)
 
     run = RunRecord(cell=cell, config=conf, mix=mix, seconds=seconds,
-                    setup_s=t_setup["end"] - PROCESS_START, peaks=peaks,
+                    setup_s=t_setup["end"] - PROCESS_START, peaks=peaks, family=family,
                     t0=w.t0, t_end=w.t_end, requests=getattr(w, "requests", []), steps=w.steps)
     device_info = {"platform": device.platform, "kind": device.device_kind,
                    "count": len(devices),

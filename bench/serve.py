@@ -18,7 +18,7 @@ from __future__ import annotations
 import gc
 import sys
 import time
-from dataclasses import dataclass, field, replace
+from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
@@ -63,34 +63,12 @@ class ServeWindow:
     drained_at: float
 
 
-def model_config(conf: Dict):
-    """The program's model configuration with every size the file states."""
-    from repro.configs import get_config
-
-    m = conf["model"]
-    return replace(
-        get_config(conf["arch"]),
-        n_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
-        n_heads=m["num_attention_heads"], n_kv_heads=m["num_key_value_heads"],
-        head_dim=m.get("head_dim", 0), d_ff=m["intermediate_size"],
-        vocab_size=m["vocab_size"], rope_theta=float(m["rope_theta"]),
-        rms_eps=float(m["rms_norm_eps"]), param_dtype=m["torch_dtype"],
-        compute_dtype=m["torch_dtype"],
-    )
-
-
-def kv_bytes_per_token(m: Dict) -> int:
-    """K and V of every layer for one token, in the served dtype."""
-    import jax.numpy as jnp
-
-    head_dim = m.get("head_dim") or m["hidden_size"] // m["num_attention_heads"]
-    return (2 * m["num_key_value_heads"] * head_dim * m["num_hidden_layers"]
-            * jnp.dtype(m["torch_dtype"]).itemsize)
-
-
 class ServeCell:
-    def __init__(self, conf: Dict, mix: Dict, reference, seed: int, seconds: float) -> None:
-        self.conf, self.mix, self.ref, self.seed = conf, mix, reference, seed
+    #: what a serving cell calls in the configuration's family module
+    NEEDS = ("program_config", "make_weights", "cache_bytes_per_token", "served_gaps")
+
+    def __init__(self, conf: Dict, mix: Dict, family, seed: int, seconds: float) -> None:
+        self.conf, self.mix, self.ref, self.seed = conf, mix, family, seed
         self.arrivals = traffic.schedule(mix, seed, seconds)
         self.prompts = traffic.prompt_tokens(seed, self.arrivals, conf["model"]["vocab_size"])
         self.seconds = seconds
@@ -107,7 +85,7 @@ class ServeCell:
         self.make_weights()
         jax.block_until_ready(self.params)
         t_w = time.perf_counter()
-        self.eng = Engine(model_config(self.conf), self.params,
+        self.eng = Engine(self.ref.program_config(self.conf), self.params,
                           ServeConfig(**self.conf["serve"]))
         self.warmup()
         log(f"set-up: weights {t_w - t:.2f} s, engine and warm-up "
@@ -206,7 +184,7 @@ class ServeCell:
         runs.  With ``control`` (calibration only) the engine is kept and
         ``self.readings`` also gets the fp8 control's reading."""
         m = self.conf["model"]
-        per_tok = kv_bytes_per_token(m)
+        per_tok = self.ref.cache_bytes_per_token(m)
         frame = self.eng.frame
         kv_bad = tok_bad = unfinished = 0
         for c in w.requests:

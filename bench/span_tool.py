@@ -175,7 +175,7 @@ def main(argv=None) -> int:
     offset = statistics.median(off)
     step_ms = [s.seconds * 1e3 for s in ring if s.name in ("engine.step", "train.step")]
     steps = len(step_ms)
-    layers = run.config["model"].get("num_hidden_layers", 0)
+    calls = getattr(run.family, "attention_calls", lambda m: 0)(run.config["model"])
     kernels = tr.matching(run.trace, r"tpu_custom_call$", float("-inf"), float("inf"))
     report = {
         "workload": args.workload, "seed": args.seed,
@@ -190,7 +190,7 @@ def main(argv=None) -> int:
         "longest_steps": longest_steps(ring, "engine.step" if "engine.step" in seen
                                        else "train.step"),
         "kernel": {"events": len(kernels), "names": sorted({n for n, _, _ in kernels}),
-                   "prefills_x_layers": layers * sum(1 for c in run.requests if c.token_times)},
+                   "prefills_x_calls": calls * sum(1 for c in run.requests if c.token_times)},
         "cost": cost(args.cost_loops),
         "result": result,
     }

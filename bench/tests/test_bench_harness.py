@@ -9,7 +9,9 @@ import json
 import math
 import re
 import time
+import types
 from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
@@ -182,7 +184,8 @@ def test_collections_are_timed_while_watched():
 
 def _run(requests, t0=0.0, t_end=10.0, **kw):
     return RunRecord(cell={}, config={"model": DS7B}, mix={}, seconds=t_end - t0,
-                     setup_s=12.5, peaks=V5E, t0=t0, t_end=t_end, requests=requests, **kw)
+                     setup_s=12.5, peaks=V5E, family=LLAMA, t0=t0, t_end=t_end,
+                     requests=requests, **kw)
 
 
 def _client(due, times, plen=256, admit=None):
@@ -211,30 +214,87 @@ def test_rates_are_taken_over_the_whole_window():
 
 # ------------------------------------------------------------------ counters
 DS7B = R.load_config("deepseek-7b-serve")["model"]
+MAMBA2 = R.load_config("mamba2-130m-train")["model"]
+LLAMA = R.load_reference({"reference": "llama"})
+MAMBA2_FAMILY = R.load_reference({"reference": "mamba2"})
 
 
 def test_flash_attention_flops_and_bytes_at_known_shapes():
-    fa = R.load_module(R.BENCH_DIR / "metrics" / "flash_attn_roofline.py")
+    fa = R.load_metric("flash_attn_roofline")
     # S=640, 32 heads of 128: QK^T and PV over 640*641/2 pairs, 2 FLOPs each
-    assert fa.flops(DS7B, 640) == 2 * 2 * 32 * 128 * (640 * 641 // 2)
+    assert LLAMA.attention_flops(DS7B, 640) == 2 * 2 * 32 * 128 * (640 * 641 // 2)
     # Q, K, V read and O written once, bf16
-    assert fa.bytes_moved(DS7B, 640) == 4 * 640 * 32 * 128 * 2
+    assert LLAMA.attention_bytes(DS7B, 640) == 4 * 640 * 32 * 128 * 2
+    # one kernel call per layer of the prefill
+    assert LLAMA.attention_calls(DS7B) == 16
     # at 640 the kernel is bandwidth-bound on a v5e
-    assert fa.least_time(DS7B, 640, V5E) == pytest.approx(4 * 640 * 4096 * 2 / 819e9)
-    assert fa.least_time(DS7B, 8192, V5E) == pytest.approx(fa.flops(DS7B, 8192) / 197e12)
+    assert fa.least_time(LLAMA, DS7B, 640, V5E) == pytest.approx(4 * 640 * 4096 * 2 / 819e9)
+    assert fa.least_time(LLAMA, DS7B, 8192, V5E) == pytest.approx(
+        LLAMA.attention_flops(DS7B, 8192) / 197e12)
 
 
 def test_model_flops_at_known_shapes():
-    mfu = R.load_module(R.BENCH_DIR / "metrics" / "mfu_pct.serve.py")
+    mfu = R.load_metric("mfu_pct.serve")
     per_layer = 4 * 4096 * 4096 + 3 * 4096 * 11008
-    assert mfu.layer_matmul_params(DS7B) == per_layer
+    assert LLAMA.layer_matmul_params(DS7B) == per_layer
     head = 2 * 4096 * 102400
-    assert mfu.prefill_flops(DS7B, 1) == 2 * per_layer * 16 + 2 * 4096 * 2 * 16 + head
-    assert mfu.decode_flops(DS7B, 99) == 2 * per_layer * 16 + 4 * 4096 * 100 * 16 + head
+    assert LLAMA.prefill_flops(DS7B, 1) == 2 * per_layer * 16 + 2 * 4096 * 2 * 16 + head
+    assert LLAMA.decode_flops(DS7B, 99) == 2 * per_layer * 16 + 4 * 4096 * 100 * 16 + head
     # one 640-token prefill and one decode token in a one-second window
     run = _run([_client(due=0.0, times=[0.5, 0.6], plen=640)], t_end=1.0)
-    want = (mfu.prefill_flops(DS7B, 640) + mfu.decode_flops(DS7B, 640)) / 197e12 * 100
+    want = (LLAMA.prefill_flops(DS7B, 640) + LLAMA.decode_flops(DS7B, 640)) / 197e12 * 100
     assert mfu.read(run) == pytest.approx(want)
+
+
+def test_training_flops_at_the_published_shape():
+    mfu = R.load_metric("mfu_pct.train")
+    # mamba2-130m: d 768, d_inner 1536, 24 heads of 64, d_state 128, one group
+    per_layer = 768 * (2 * 1536 + 2 * 128 + 24) + 1536 * 768
+    assert MAMBA2_FAMILY.matmul_params(MAMBA2) == 24 * per_layer + 50277 * 768
+    # per layer, chunks of 256: (256 + 1) / 2 causal pairs a token
+    ssd = 24 * (2 * 128.5 * 128 + 2 * 128.5 * 64 * 24 + 4 * 128 * 64 * 24)
+    assert MAMBA2_FAMILY.ssd_flops_per_token(MAMBA2) == ssd
+    want = 6 * (24 * per_layer + 50277 * 768) + 3 * ssd
+    assert MAMBA2_FAMILY.train_flops_per_token(MAMBA2) == want == 859_663_872
+    # 1000 tokens a second for two seconds
+    steps = [SimpleNamespace(tokens=1000), SimpleNamespace(tokens=1000)]
+    run = RunRecord(cell={}, config={"model": MAMBA2}, mix={}, seconds=2.0, setup_s=1.0,
+                    peaks=V5E, family=MAMBA2_FAMILY, t0=0.0, t_end=2.0, steps=steps)
+    assert mfu.read(run) == pytest.approx(100.0 * want * 1000 / 197e12)
+
+
+# ------------------------------------------------------------------ family modules
+def _all_metrics(man, cell):
+    return R.metrics_for(man, cell, False) + R.metrics_for(man, cell, True)
+
+
+def test_every_configuration_has_a_family_module_with_what_its_cells_call():
+    man = R.load_manifest()
+    for cell in man["workloads"]:
+        conf = R.load_config(cell["config"])
+        R.check_family(R.load_reference(conf), conf["kind"], _all_metrics(man, cell["name"]))
+
+
+def test_a_missing_function_is_an_error_before_model_work(monkeypatch):
+    man = R.load_manifest()
+    cell = R.find_cell(man, "ds7b-prefill-burst")
+    conf = R.load_config(cell["config"])
+    lacking = types.ModuleType("lacking")
+    for name in dir(LLAMA):
+        if name != "decode_flops" and not name.startswith("__"):
+            setattr(lacking, name, getattr(LLAMA, name))
+    monkeypatch.setattr(R, "load_reference", lambda conf: lacking)
+    # the look for a chip comes first in a run, before any model work: the
+    # family's error is raised before it (the host has no chip)
+    with pytest.raises(AttributeError, match=r"lacking lacks decode_flops \(for mfu_pct.serve\)"):
+        R.run_cell(cell, conf, traffic.load_mix(cell["traffic"]),
+                   R.metrics_for(man, cell["name"], True), 1, 1.0, True)
+    # a run whose metrics do not call it gets past the family, to the chip
+    with pytest.raises(R.NoChip):
+        R.run_cell(cell, conf, traffic.load_mix(cell["traffic"]),
+                   R.metrics_for(man, cell["name"], False), 1, 1.0, False)
+    with pytest.raises(AttributeError, match="cache_bytes_per_token \\(for serve cells\\)"):
+        R.check_family(types.ModuleType("empty"), "serve", [])
 
 
 # ------------------------------------------------------------------ trace
@@ -276,4 +336,4 @@ def test_reduction_on_the_recorded_trace(recorded):
     assert {"engine_step.admit", "collect"} <= names
     fa = R.load_module(R.BENCH_DIR / "metrics" / "flash_attn_roofline.py")
     kernels = tr.matching(recorded, fa.KERNEL, lo, hi)
-    assert kernels and len(kernels) % DS7B["num_hidden_layers"] == 0
+    assert kernels and len(kernels) % LLAMA.attention_calls(DS7B) == 0

@@ -42,25 +42,6 @@ class TrainWindow:
     drained_at: float
 
 
-def model_config(conf: Dict):
-    """The program's model configuration with every size the file states."""
-    from dataclasses import replace
-
-    from repro.configs import SSMConfig, get_config
-
-    m = conf["model"]
-    return replace(
-        get_config(conf["arch"]),
-        n_layers=m["n_layer"], d_model=m["d_model"], vocab_size=m["vocab_size"],
-        rms_eps=float(m["rms_norm_eps"]),
-        ssm=SSMConfig(d_state=m["d_state"], expand=m["expand"], head_dim=m["headdim"],
-                      n_groups=m["ngroups"], conv_width=m["d_conv"], chunk=m["chunk_size"],
-                      dt_min=m["dt_min"], dt_max=m["dt_max"]),
-        param_dtype=conf["train"]["param_dtype"], compute_dtype=conf["train"]["compute_dtype"],
-        remat=conf["train"]["remat"],
-    )
-
-
 def train_config(conf: Dict):
     from repro.optim import AdamWConfig, ScheduleConfig
     from repro.train.trainer import TrainConfig
@@ -111,8 +92,11 @@ def leaf_gaps(got: List[float], ref: List[float], keep=None) -> List[float]:
 
 
 class TrainCell:
-    def __init__(self, conf: Dict, mix: Dict, reference, seed: int, seconds: float) -> None:
-        self.conf, self.mix, self.ref, self.seed, self.seconds = conf, mix, reference, seed, seconds
+    #: what a training cell calls in the configuration's family module
+    NEEDS = ("program_config", "make_weights", "train")
+
+    def __init__(self, conf: Dict, mix: Dict, family, seed: int, seconds: float) -> None:
+        self.conf, self.mix, self.ref, self.seed, self.seconds = conf, mix, family, seed, seconds
         self.batch = conf["train"]["global_batch"]
         self.seq = mix["seq_len"]
         self.vocab = conf["model"]["vocab_size"]
@@ -124,7 +108,8 @@ class TrainCell:
 
         t = time.perf_counter()
         self.feed = Feed(self.seed, self.batch, self.seq, self.vocab)
-        self.trainer = Trainer(model_config(self.conf), train_config(self.conf), self.feed)
+        self.trainer = Trainer(self.ref.program_config(self.conf), train_config(self.conf),
+                               self.feed)
         self.first_steps()
         log(f"set-up: weights and the first three steps {time.perf_counter() - t:.2f} s")
 
